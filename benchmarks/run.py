@@ -36,6 +36,8 @@ def main():
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (accuracy, block_vs_deflation, disk_tier,
                             oom_batching, precision, roofline,
                             scaling_dense, scaling_sparse, serving,

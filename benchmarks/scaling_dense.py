@@ -86,6 +86,8 @@ import json; print("RESULT:" + json.dumps(results))
 
 def measured_emulated():
     env = dict(os.environ)
+    # fake CPU devices: the child must never reach for the parent's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")

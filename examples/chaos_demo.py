@@ -19,7 +19,10 @@ Three legs, all verified against an uninterrupted fault-free reference:
    sigmas and conserved pass accounting.
 
 The child/parent split uses the ``REPRO_CHAOS_ROLE`` env var; CI runs
-this file as its kill-under-injected-fault two-process smoke.
+this file as its kill-under-injected-fault two-process smoke.  The
+child runs FIRST, before the parent touches JAX: on an accelerator a
+parent that has initialized JAX holds the device, and the child could
+not get it.
 """
 import os
 import subprocess
@@ -67,6 +70,18 @@ def main():
     A = make_matrix()
     workdir = tempfile.mkdtemp(prefix="chaos_demo_")
     path = stage_to_disk(A, os.path.join(workdir, "a.npy"))
+
+    # -- leg 3, first half: the child dies mid-solve (no JAX here yet) --
+    ckpt = os.path.join(workdir, "ckpt")
+    env = dict(os.environ, REPRO_CHAOS_ROLE="child")
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), path, ckpt], env=env)
+    assert proc.returncode == EXIT_CODE, \
+        f"child exited {proc.returncode}, wanted {EXIT_CODE}"
+    steps = [n for n in os.listdir(ckpt) if n.startswith("step_")]
+    print(f"kill: child died with os._exit({EXIT_CODE}), "
+          f"checkpoints survived: {sorted(steps)}")
+
     ref = solve(path)
     print(f"reference: converged={ref.converged} "
           f"passes={ref.passes_over_A} backend={ref.backend}")
@@ -97,16 +112,7 @@ def main():
     print(f"device-OOM: demoted dense->{res.backend}, sigmas agree, "
           f"faults={res.faults['counters']}")
 
-    # -- leg 3: real kill under injected fault, then resume -------------
-    ckpt = os.path.join(workdir, "ckpt")
-    env = dict(os.environ, REPRO_CHAOS_ROLE="child")
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), path, ckpt], env=env)
-    assert proc.returncode == EXIT_CODE, \
-        f"child exited {proc.returncode}, wanted {EXIT_CODE}"
-    steps = [n for n in os.listdir(ckpt) if n.startswith("step_")]
-    print(f"kill: child died with os._exit({EXIT_CODE}), "
-          f"checkpoints survived: {sorted(steps)}")
+    # -- leg 3, second half: resume the killed solve ---------------------
     with inject_faults(FaultPlan(FaultSpec(site="disk_read", at=1))):
         res = solve(path, ckpt=ckpt)
     assert np.array_equal(np.asarray(ref.S), np.asarray(res.S))

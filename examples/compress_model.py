@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
 from repro.core import svd
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
@@ -30,7 +29,7 @@ def main():
                       vocab_size=4096, dtype="float32", scan_layers=False)
     params = T.init_model(jax.random.PRNGKey(0), cfg)
 
-    flat, treedef = tree_flatten_with_path(params)
+    flat, treedef = jax.tree.flatten_with_path(params)
     total_before = total_after = 0
     print(f"{'weight':<44} {'shape':>16} {'rank':>5} {'rel err':>9} {'ratio':>7}")
     new_leaves = []

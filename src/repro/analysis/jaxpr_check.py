@@ -87,8 +87,15 @@ def iter_eqns(jaxpr):
             yield from iter_eqns(sub)
 
 
+#: primitives reported under another name: inside ``shard_map`` jax
+#: binds ``psum`` of a varying value as ``psum_invariant``.  ``pvary``
+#: (a type cast with no data movement) is never a collective.
+_PRIM_ALIASES = {"psum_invariant": "psum"}
+
+
 def _prim(eqn) -> str:
-    return eqn.primitive.name.replace("-", "_")
+    name = eqn.primitive.name.replace("-", "_")
+    return _PRIM_ALIASES.get(name, name)
 
 
 def _np_dtype(aval):

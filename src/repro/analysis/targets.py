@@ -124,7 +124,6 @@ def _sharded_targets():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map as _shard_map
     from repro.core.dist_svd import _deflated_chain_step
     from repro.core.operator import (ShardedOperator, sharded_block_step_fn,
                                      sharded_extract_fn, sharded_sketch_fn)
@@ -178,7 +177,7 @@ def _sharded_targets():
 
     def deflation_step(faithful):
         @functools.partial(
-            _shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(row, row, P(None), P(None, None), P(None)),
             out_specs=P(None))
         def power_step(A_loc, U_loc, S, V, v):

@@ -26,7 +26,6 @@ import zipfile
 import jax
 import numpy as np
 
-from repro import compat
 from repro.core.errors import CheckpointCorruptError
 from repro.core.faults import fault_hook
 
@@ -56,7 +55,7 @@ def _fsync_dir(path: str) -> None:
 
 
 def _flatten(tree):
-    flat, treedef = compat.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     keys = ["/".join(str(k) for k in path) for path, _ in flat]
     vals = [v for _, v in flat]
     return keys, vals, treedef

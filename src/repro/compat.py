@@ -1,159 +1,64 @@
-"""Compatibility layer over the installed jax version.
+"""Adapters over the installed jax (0.9) where a call site needs one.
 
-The codebase is written against the current jax API (``jax.shard_map``,
-``jax.lax.pvary``, ``jax.lax.all_gather_invariant``, typed mesh axes).
-Older pinned jax releases (0.4.x) predate all four; this module provides
-the exact fallbacks so every call site can import from one place:
+Everything else calls jax directly (``jax.shard_map``,
+``jax.sharding.get_abstract_mesh``/``set_mesh``/``AbstractMesh``,
+``jax.tree.flatten_with_path``).  What is left here is behaviour jax
+does not offer under a public name:
 
-* ``shard_map``       — ``jax.shard_map`` or ``jax.experimental.shard_map``.
-* ``pvary``           — identity on pre-vma jax (the varying-manual-axes
-  type system the real ``pvary`` feeds does not exist there).
-* ``all_gather_inv``  — ``all_gather_invariant`` where present, else plain
-  ``all_gather`` (whose output is already treated as replicated by the
-  older shard_map replication checker).
-* ``AxisType`` / ``make_mesh`` — typed mesh axes where supported, silently
-  dropped otherwise (0.4.x meshes behave as Auto).
+* ``pvary``           — ``jax.lax.pcast(..., to="varying")`` that skips
+  leaves already varying over the requested axes (the raw call rejects
+  them).
+* ``all_gather_inv``  — ``all_gather_invariant``, which jax 0.9 keeps
+  under ``jax._src``.
+* ``make_mesh``       — ``jax.make_mesh`` with Auto axes by default
+  (jax 0.9 defaults to Explicit axes).
+* ``manual_axis_names`` — the axes a ``shard_map`` body has bound
+  manually (from the core axis env).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax._src.lax.parallel import all_gather_invariant as all_gather_inv
+from jax.sharding import AxisType
 
-try:  # jax >= 0.6: top-level export with axis_names= partial-manual API
-    _new_shard_map = jax.shard_map
-
-    def shard_map(f, **kwargs):
-        return _new_shard_map(f, **kwargs)
-
-except AttributeError:  # 0.4.x: experimental module, auto= complement API
-    from jax.experimental.shard_map import shard_map as _ex_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, **kwargs):
-        if axis_names is not None:
-            # new API names the MANUAL axes; old API names the AUTO ones
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            kwargs.setdefault("auto", auto)
-        # 0.4.x replication checking lacks rules for while/scan bodies
-        # (jax#workaround in the error message itself): disable it.
-        kwargs.setdefault("check_rep", False)
-        return _ex_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-
-# Partial-manual shard_map (manual over a subset of axes) with control
-# flow in the body hard-crashes the 0.4.x XLA SPMD partitioner
-# (hlo_sharding_util CHECK IsManualSubgroup); only the new API supports it.
-SUPPORTS_PARTIAL_MANUAL = hasattr(jax, "shard_map")
-
-try:
-    _pvary_raw = jax.lax.pvary
-
-    def pvary(x, axis_name):
-        """``jax.lax.pvary`` that tolerates already-varying leaves.
-
-        Warm-started block iterates are built from psum outputs, so parts
-        of a while_loop carry can already vary over the mesh axes; the raw
-        ``pvary`` rejects that.  Per leaf, only the axes missing from the
-        aval's vma set are added (leaves without vma typing fall through
-        to the raw call, preserving the original behaviour).
-        """
-        axes = tuple(axis_name) if isinstance(axis_name, (tuple, list)) \
-            else (axis_name,)
-
-        def _one(v):
-            vma = getattr(getattr(v, "aval", None), "vma", None)
-            if vma is None:
-                return _pvary_raw(v, axes)
-            missing = tuple(a for a in axes if a not in vma)
-            return _pvary_raw(v, missing) if missing else v
-
-        return jax.tree_util.tree_map(_one, x)
-
-except AttributeError:  # pre-vma jax: values are not vma-typed; no-op
-    def pvary(x, axis_name):  # noqa: ARG001
-        return x
-
-try:
-    from jax.lax import all_gather_invariant as all_gather_inv
-except ImportError:
-    try:  # some 0.8.x builds keep it under _src
-        from jax._src.lax.parallel import all_gather_invariant as all_gather_inv
-    except ImportError:  # 0.4.x: plain all_gather is replication-checked
-        def all_gather_inv(x, axis_name, *, tiled=False):
-            return jax.lax.all_gather(x, axis_name, tiled=tiled)
-
-try:
-    from jax.sharding import AxisType
-    _HAS_AXIS_TYPES = True
-except ImportError:
-    class AxisType:  # sentinel so call sites can still name Auto axes
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _HAS_AXIS_TYPES = False
+__all__ = ["pvary", "all_gather_inv", "make_mesh", "manual_axis_names"]
 
 
-def make_mesh(shape, axes, axis_types=None):
-    """``jax.make_mesh`` that tolerates jax versions without axis_types."""
-    if _HAS_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
+def pvary(x, axis_name):
+    """Mark ``x`` varying over ``axis_name``, tolerating leaves that
+    already vary.
 
-
-def AbstractMesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across constructor generations.
-
-    New jax takes ``(axis_sizes, axis_names)``; 0.4.x takes one
-    ``((name, size), ...)`` shape tuple.
+    Warm-started block iterates are built from psum outputs, so parts
+    of a while_loop carry can already vary over the mesh axes; the raw
+    cast rejects that.  Per leaf, only the axes missing from the aval's
+    vma set are added.
     """
-    try:
-        return jax.sharding.AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    axes = tuple(axis_name) if isinstance(axis_name, (tuple, list)) \
+        else (axis_name,)
+
+    def _one(v):
+        vma = getattr(getattr(v, "aval", None), "vma", None) or ()
+        missing = tuple(a for a in axes if a not in vma)
+        return jax.lax.pcast(v, missing, to="varying") if missing else v
+
+    return jax.tree_util.tree_map(_one, x)
 
 
-try:
-    tree_flatten_with_path = jax.tree.flatten_with_path
-except AttributeError:  # 0.4.x keeps it in jax.tree_util only
-    from jax.tree_util import tree_flatten_with_path
-
-
-def get_abstract_mesh():
-    """Ambient mesh: abstract on new jax, the physical context mesh on old.
-
-    Both return objects expose ``.empty``, ``.axis_names`` and ``.shape``;
-    ``.axis_types`` only exists on new jax — call sites getattr-guard it.
-    """
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        from jax.interpreters import pxla
-        return pxla.thread_resources.env.physical_mesh
+def make_mesh(shape, axes, axis_types=None, *, devices=None):
+    """``jax.make_mesh`` with Auto axes unless ``axis_types`` says
+    otherwise."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=axis_types,
+                         devices=devices)
 
 
 def manual_axis_names() -> set:
-    """Mesh axes bound manually at trace time (inside a shard_map body).
-
-    New jax exposes this through the abstract mesh's axis types; old jax
-    only through the core axis env — used so sharding constraints never
-    name an axis that shard_map already made manual.
-    """
+    """Mesh axes bound manually at trace time (inside a shard_map body),
+    so sharding constraints never name an axis that shard_map already
+    made manual."""
     try:
         from jax._src.core import get_axis_env
         return set(getattr(get_axis_env(), "axis_sizes", {}).keys())
     except Exception:
         return set()
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Install ``mesh`` as the ambient mesh for constraints and jit."""
-    try:
-        ctx = jax.sharding.set_mesh(mesh)
-    except AttributeError:  # 0.4.x: Mesh is itself the context manager
-        ctx = mesh
-    with ctx:
-        yield mesh

@@ -47,7 +47,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # varying -> invariant all-gather (replicated output) + version shims
 from repro.compat import all_gather_inv as _all_gather_inv
 from repro.compat import pvary as _pvary
-from repro.compat import shard_map as _shard_map
 from repro.core.config import SVDConfig, SVDResult
 
 #: Back-compat alias — the per-backend result NamedTuples were unified.
@@ -188,7 +187,7 @@ def _dist_deflation(
     row_spec = P(axes if len(axes) > 1 else axes[0], None)
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(row_spec, P(None)),
         out_specs=(row_spec, P(None), P(None, None), P(None), P(None)),

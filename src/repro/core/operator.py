@@ -77,7 +77,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.config import seed_to_key
 from repro.core.precision import resolve_sweep_dtype
 from repro.core.tsvd import (rayleigh_ritz_from_W, sweep_ops,
@@ -127,8 +126,11 @@ def _gap(Q, Qn):
     # sum of squared sines of the principal angles between span(Q) and
     # span(Qn): invariant to rotations within the subspace, so it settles
     # even when singular values are clustered (per-column |v . v1| tests
-    # never do).  Returned unsynced — a device scalar the driver floats.
-    return Q.shape[1] - jnp.sum((Q.T @ Qn) ** 2)
+    # never do).  Taken as ||Qn - Q Q^T Qn||_F^2, not l - ||Q^T Qn||_F^2:
+    # the difference form cancels to fp32 rounding noise (~1e-5 at
+    # n = 32768 on a TPU), a floor at the driver's eps * l tolerance.
+    # Returned unsynced — a device scalar the driver floats.
+    return jnp.sum((Qn - Q @ (Q.T @ Qn)) ** 2)
 
 
 @functools.partial(jax.jit, static_argnames=("sweep_dtype",))
@@ -315,7 +317,8 @@ class LinearOperator:
         return _orth(X)
 
     def subspace_gap(self, Q, Qn):
-        """Rotation-invariant gap ``l - ||Q^T Qn||_F^2`` (may return an
+        """Rotation-invariant gap ``||Qn - Q Q^T Qn||_F^2`` (equal to
+        ``l - ||Q^T Qn||_F^2`` for orthonormal bases; may return an
         unsynced device scalar; the driver floats it)."""
         return _gap(Q, Qn)
 
@@ -455,7 +458,7 @@ def sharded_gram_chain_fn(mesh, axes, sweep_dtype):
     analyzed collective schedule can't drift from the driver."""
     spec = _row_spec(axes)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, P(None, None)),
                        out_specs=P(None, None))
     def gram_chain(A_loc, Q):
@@ -487,7 +490,7 @@ def sharded_sketch_fn(mesh, axes, l, sweep_dtype):
     into the key), so the ``(m, l)`` Omega is never resident anywhere."""
     spec = _row_spec(axes)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, P(None)),
                        out_specs=P(None, None))
     def sketch(A_loc, seed_arr):
@@ -507,7 +510,7 @@ def sharded_sketch_fn(mesh, axes, l, sweep_dtype):
 def sharded_matmat_fn(mesh, axes):
     spec = _row_spec(axes)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, P(None, None)), out_specs=spec)
     def matmat(A_loc, Q):
         return A_loc.astype(jnp.float32) @ Q
@@ -519,7 +522,7 @@ def sharded_matmat_fn(mesh, axes):
 def sharded_rmatmat_fn(mesh, axes):
     spec = _row_spec(axes)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec), out_specs=P(None, None))
     def rmatmat(A_loc, Y_loc):
         return jax.lax.psum(A_loc.astype(jnp.float32).T @ Y_loc, axes)
@@ -535,7 +538,7 @@ def sharded_extract_fn(mesh, axes):
     truncates to k."""
     spec = _row_spec(axes)
 
-    @functools.partial(_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, P(None, None)),
                        out_specs=(spec, P(None), P(None, None)))
     def extract(A_loc, Q):
@@ -701,6 +704,7 @@ class HostBlockedOperator(LinearOperator):
         for b in range(host.n_blocks):     # one pass; Omega never resident
             cur = nxt
             if b + 1 < host.n_blocks:      # prefetch next block (async H2D)
+                jax.block_until_ready(acc)     # pace: block b-1 consumed
                 nxt = host.block(b + 1)
             om_b = jax.random.normal(jax.random.fold_in(okey, b),
                                      (cur.shape[0], l), jnp.float32)

@@ -40,9 +40,23 @@ wide the elements are — bf16 changes the *bytes per pass* (2 instead of
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 SWEEP_DTYPES = ("float32", "bfloat16")
+
+
+def fp32_dots():
+    """Context in which every fp32 ``dot`` is computed in fp32.
+
+    At its DEFAULT precision XLA:TPU feeds float32 dot operands through
+    ONE bfloat16 MXU pass, so an "fp32" sweep, the subspace gap and the
+    Rayleigh–Ritz extraction would carry bf16 rounding (~4e-3) and the
+    gap test could never reach ``eps``.  The solver entry points trace
+    under this context; bf16 sweeps are unaffected (their operands
+    already are bf16), and on the CPU nothing changes.
+    """
+    return jax.default_matmul_precision("highest")
 
 
 def resolve_sweep_dtype(sweep_dtype) -> jnp.dtype:

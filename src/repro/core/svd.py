@@ -76,7 +76,7 @@ from repro.core.operator import (DenseOperator, HostBlockedOperator,
                                  LinearOperator, ShardedOperator,
                                  SparseStreamOperator, host_sync_scalar,
                                  warm_start_width)
-from repro.core.precision import resolve_sweep_dtype
+from repro.core.precision import fp32_dots, resolve_sweep_dtype
 
 __all__ = ["svd", "svd_update", "init_state", "step", "finalize",
            "SolverState", "SVDConfig", "SVDResult", "key_to_seed"]
@@ -179,7 +179,7 @@ def _check_health(g: float, width: int, where: str) -> None:
 
     The gap is the one host-visible per-iteration scalar, and it is a
     perfect canary: any NaN/Inf anywhere in the iterate poisons the
-    ``l - ||Q^T Qn||_F^2`` reduction, and a finite value outside
+    ``||Qn - Q Q^T Qn||_F^2`` reduction, and a finite value outside
     ``[0, l]`` means the bases stopped being orthonormal.  Before this
     guard a NaN gap silently never satisfied ``gap <= tol`` — the solve
     would burn ``max_iters`` on garbage and return NaN factors.
@@ -624,7 +624,11 @@ def _dense_svd(A, k: int, cfg: SVDConfig, warm=None) -> SVDResult:
 
 def _sharded_svd(A, k: int, mesh, axes, cfg: SVDConfig,
                  warm=None) -> SVDResult:
-    A = jnp.asarray(A)
+    if not isinstance(A, jax.Array):
+        # host input: orient it on the host and let ShardedOperator put
+        # each row slab straight onto its own device — never the whole
+        # matrix on one chip first
+        A = np.asarray(A)
     m, n = A.shape
     transposed = m < n                      # CSVD orientation: swap out
     if transposed:
@@ -891,8 +895,9 @@ def svd(A, k: int, *, mesh=None, axes=("data",),
     wall_time_s).
     """
     t0 = time.perf_counter()
-    res = _dispatch(A, k, mesh=mesh, axes=axes, config=config,
-                    _warm=_warm, **overrides)
+    with fp32_dots():
+        res = _dispatch(A, k, mesh=mesh, axes=axes, config=config,
+                        _warm=_warm, **overrides)
     # one stamp at the front door covers every backend: metering layers
     # (repro.serving) read the wall clock off the result instead of
     # timing the driver from outside

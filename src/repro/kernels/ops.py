@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles shape padding to tile boundaries, CPU fallback (interpret mode —
-this container has no TPU; ``interpret=True`` executes the kernel body in
-Python for correctness), and sensible tile defaults per op.
+Handles shape padding to tile boundaries, interpret mode on the CPU
+backend only (``interpret=True`` executes the kernel body in Python for
+correctness; any other backend compiles), and sensible tile defaults
+per op.
 """
 from __future__ import annotations
 
@@ -18,8 +19,10 @@ from repro.kernels import local_attn as _la
 from repro.kernels import ref as _ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode is the CPU's stand-in for Mosaic; every other
+    backend compiles the kernel (and fails loudly if it cannot)."""
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
@@ -45,7 +48,7 @@ def gram(A: jax.Array, *, bn: int = 256, bk: int = 512,
     zero, and the result is cropped back to (n, n).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     m, n = A.shape
     bn_eff = min(bn, max(128, 1 << (n - 1).bit_length()))
     Ap = _pad_to(A, (bk, bn_eff))
@@ -57,7 +60,7 @@ def gram(A: jax.Array, *, bn: int = 256, bk: int = 512,
 def matvec(A: jax.Array, v: jax.Array, *, bm: int = 512, bn: int = 512,
            interpret: bool | None = None) -> jax.Array:
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     m, n = A.shape
     Ap = _pad_to(A, (bm, bn))
     vp = _pad_to(v, (bn,))
@@ -73,7 +76,7 @@ def deflate_rmatvec(A, U, Xv, SVtv, *, bm: int = 512, bn: int = 512,
     the extra ``utxv`` rows they produce are zero — cropped on return.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     m, n = A.shape
     k = U.shape[1]
     Ap = _pad_to(A, (bm, bn))
@@ -96,7 +99,7 @@ def block_matvec(A, Q, *, bm: int = 512, bn: int = 512,
     fp32 accumulate).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     m, n = A.shape
     k = Q.shape[1]
     Ap = _pad_to(A, (bm, bn))
@@ -113,7 +116,7 @@ def block_rmatvec(A, Y, *, bm: int = 512, bn: int = 512,
     ``block_matvec`` for the ``dtype`` policy.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     m, n = A.shape
     k = Y.shape[1]
     Ap = _pad_to(A, (bm, bn))
@@ -133,7 +136,7 @@ def block_gram_chain(A, Q, *, bm: int = 512, bn: int = 512,
     a 2-byte ``A`` while accumulating fp32.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     m, n = A.shape
     k = Q.shape[1]
     Ap = _pad_to(A, (bm, bn))
@@ -152,7 +155,7 @@ def local_attention(q, k, v, *, window: int, softcap: float | None = None,
     causal mask removes them — exactness is asserted in the tests.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret()
     B, H, S, D = q.shape
     qp = _pad_to(q, (1, 1, bq, 1))
     kp = _pad_to(k, (1, 1, bk, 1))

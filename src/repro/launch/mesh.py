@@ -14,14 +14,13 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh
 
-from repro.compat import AxisType, make_mesh
+from repro.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
-                     axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1) -> Mesh:
@@ -29,5 +28,4 @@ def make_host_mesh(data: int | None = None, model: int = 1) -> Mesh:
     n = jax.device_count()
     if data is None:
         data = n // model
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return make_mesh((data, model), ("data", "model"))

@@ -45,7 +45,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.analysis.jaxpr_check import (StepContract,  # noqa: E402
                                         check_step, trace_jaxpr)
-from repro.compat import shard_map as _shard_map  # noqa: E402
 from repro.core.dist_svd import (_deflated_chain_step,  # noqa: E402
                                  _all_gather_inv)
 from repro.core.operator import (sharded_block_step_fn,  # noqa: E402
@@ -100,7 +99,7 @@ def variant_fn_args(mesh, kind: str, faithful: bool):
     row_spec = P(axes, None)
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(row_spec, row_spec, P(None), P(None, None), P(None)),
         out_specs=P(None))
     def power_step(A_loc, U_loc, S, V, v):

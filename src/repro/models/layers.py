@@ -15,12 +15,12 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro import compat, sharding
+from repro import sharding
 from repro.models.config import ModelConfig
 
 
 def _axis_size(name: str) -> int:
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or name not in mesh.axis_names:
         return 1
     return mesh.shape[name]

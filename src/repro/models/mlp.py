@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, sharding
+from repro import sharding
 from repro.models.config import ModelConfig
 
 
@@ -168,7 +168,7 @@ def _moe_local(x, router, w_gate, w_in, w_out, *, cfg: ModelConfig,
 
 def apply_moe(p, cfg: ModelConfig, x: jax.Array):
     """MoE FFN; returns (y, aux_loss). Runs per-shard via shard_map."""
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or not mesh.axis_names:
         # single-device path (tests)
         y, aux = _moe_local(x, p["router"], p["w_gate"], p["w_in"],
@@ -191,7 +191,7 @@ def apply_moe(p, cfg: ModelConfig, x: jax.Array):
 
     fn = functools.partial(_moe_local, cfg=cfg, batch_axes=batch_axes,
                            data_axes=data_axes, tp_axis=tp_axis)
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec, w_spec, wo_spec),
         out_specs=(x_spec, P()),

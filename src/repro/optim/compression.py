@@ -28,7 +28,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 PyTree = Any
 
@@ -53,7 +52,7 @@ def _mat_shape(shape: tuple[int, ...]) -> tuple[int, int] | None:
 
 def init_state(params: PyTree, cfg: CompressionConfig) -> PyTree:
     """Warm-start Q subspaces + error buffers per compressible leaf."""
-    flat, treedef = compat.tree_flatten_with_path(params)
+    flat, treedef = jax.tree.flatten_with_path(params)
     qs, errs = [], []
     for i, (path, p) in enumerate(flat):
         ms = _mat_shape(p.shape)

@@ -22,6 +22,7 @@ import sys
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.config import SVDConfig
 from repro.serving import JobStatus, SVDService
 
@@ -44,6 +45,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="number of large streamed jobs")
     ap.add_argument("--workers", type=int, default=2)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     sm, sn, sk = (48, 24, 4) if args.smoke else (128, 64, 8)

@@ -43,15 +43,16 @@ import numpy as np
 from repro.core.config import SVDResult, seed_to_key
 from repro.core.errors import NumericalHealthError
 from repro.core.operator import warm_start_width
-from repro.core.precision import resolve_sweep_dtype
+from repro.core.precision import fp32_dots, resolve_sweep_dtype
 from repro.core.tsvd import rayleigh_ritz_from_W, sweep_ops
 
 __all__ = ["batch_key", "batchable", "solve_batch",
            "batched_block_solve_fn", "MAX_BATCH_ELEMS"]
 
-#: lanes bigger than this are not worth stacking (the solve dominates
-#: the dispatch overhead; they also inflate the batch's memory peak)
-MAX_BATCH_ELEMS = 1 << 18
+#: lanes bigger than this (16 MiB of fp32) are not worth stacking: the
+#: solve dominates the dispatch overhead, and they inflate the batch's
+#: memory peak
+MAX_BATCH_ELEMS = 1 << 22
 
 
 def batchable(spec) -> bool:
@@ -107,7 +108,7 @@ def _batched_block_solve_fn(m: int, n: int, k: int, l: int,
     recurring burst shape compiles exactly once per B.
 
     The iteration mirrors ``core/svd.py::step`` in its unlagged form:
-    ``Q <- orth(A^T A Q)``, gap ``l - ||Q^T Qn||_F^2``, stop per lane at
+    ``Q <- orth(A^T A Q)``, gap ``||Qn - Q Q^T Qn||_F^2``, stop per lane at
     ``gap <= eps * l``.  Non-finite gaps also stop the lane (so a NaN
     lane cannot spin its batchmates to max_iters); the caller maps those
     lanes to typed failures.
@@ -133,8 +134,8 @@ def _batched_block_solve_fn(m: int, n: int, k: int, l: int,
 
     def gaps(Q, Qn):
         # per-lane rotation-invariant subspace gap (cf. operator._gap)
-        return Q.shape[-1] - jnp.sum(
-            jnp.einsum("bij,bik->bjk", Q, Qn) ** 2, axis=(1, 2))
+        R = Qn - Q @ jnp.einsum("bij,bik->bjk", Q, Qn)
+        return jnp.sum(R ** 2, axis=(1, 2))
 
     def solve(X, keys):
         if warmup_q > 0:
@@ -209,7 +210,8 @@ def solve_batch(specs: list) -> list[tuple[Any, BaseException | None]]:
                       for s in specs])
     fn = batched_block_solve_fn(m, n, k, l, sd, float(cfg0.eps),
                                 int(cfg0.max_iters), int(cfg0.warmup_q))
-    U, S, V, iters, gap, conv = fn(X, keys)
+    with fp32_dots():
+        U, S, V, iters, gap, conv = fn(X, keys)
     U, S, V = np.asarray(U), np.asarray(S), np.asarray(V)
     iters = np.asarray(iters)
     conv = np.asarray(conv)

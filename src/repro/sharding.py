@@ -58,7 +58,7 @@ _STATE = threading.local()
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh):
     """Install ``mesh`` as the ambient mesh for ``constrain`` and jit."""
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         yield mesh
 
 
@@ -142,13 +142,8 @@ def constrain(x: jax.Array, *logical: str | None) -> jax.Array:
 
     No-op outside a mesh context (unit tests on one device).
     """
-    mesh = compat.get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or not mesh.axis_names:
-        return x
-    if not compat._HAS_AXIS_TYPES and compat.manual_axis_names():
-        # Old jax/XLA cannot mix GSPMD constraints with a partial-manual
-        # shard_map region (hlo_sharding_util CHECK) — let auto sharding
-        # propagate instead of constraining.
         return x
     spec = resolve_spec(tuple(logical), mesh, tuple(x.shape))
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

@@ -116,7 +116,7 @@ def _grads_and_metrics(params, cfg: ModelConfig, batch, n_micro: int):
     init = (g0, jnp.float32(0))
     # Inside a partial-manual shard_map (pod-compressed mode) the per-pod
     # grads/loss are mesh-varying; mark the scan init to match.
-    am = compat.get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is not None and not am.empty:
         manual = tuple(n for n, t in zip(am.axis_names,
                                          getattr(am, "axis_types", ()))
@@ -134,42 +134,16 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh | None):
     use_pod_compression = (
         tc.compression.enabled and mesh is not None
         and "pod" in mesh.axis_names)
-    # Per-pod error-feedback state is stacked over the pod axis whenever
-    # the mesh has one (init_train_state); remember how to (un)stack it
-    # for the degraded single-program path below.
-    pod_stacked = use_pod_compression
-    npods = mesh.shape["pod"] if pod_stacked else 1
-    if use_pod_compression and not compat.SUPPORTS_PARTIAL_MANUAL:
-        # Old jax/XLA cannot run a partial-manual shard_map around a
-        # scanned transformer (SPMD partitioner CHECK): degrade to
-        # single-program compression — identical update when pods see
-        # identical programs; only the per-pod gradient divergence in the
-        # error buffers is lost.
-        use_pod_compression = False
 
     if not use_pod_compression:
-        _istuple = lambda x: isinstance(x, tuple)
-
         def step(state: TrainState, batch):
             grads, metrics = _grads_and_metrics(
                 state.params, cfg, batch, tc.microbatches)
             cstate = state.comp
             if tc.compression.enabled:
-                cstate = dict(cstate)
-                if pod_stacked:  # (npods, ...) -> (...): degraded mode
-                    cstate["err"] = jax.tree.map(
-                        lambda e: e if isinstance(e, tuple) else e[0],
-                        cstate["err"], is_leaf=_istuple)
                 grads, cstate, cs = comp.compress_grads(
-                    grads, cstate, tc.compression, axis_name=None)
+                    grads, dict(cstate), tc.compression, axis_name=None)
                 metrics.update(cs)
-                if pod_stacked:
-                    cstate = dict(cstate)
-                    cstate["err"] = jax.tree.map(
-                        lambda e: (e if isinstance(e, tuple) else
-                                   jnp.broadcast_to(e[None],
-                                                    (npods,) + e.shape)),
-                        cstate["err"], is_leaf=_istuple)
             params, ostate, om = opt.apply_updates(
                 state.params, grads, state.opt, tc.adamw)
             metrics.update(om)
@@ -212,7 +186,7 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh | None):
                      "err": jax.tree.map(
                          lambda e: () if isinstance(e, tuple) else P("pod"),
                          state.comp["err"], is_leaf=_istuple)}
-        params, ostate, cstate, step_ct, metrics = compat.shard_map(
+        params, ostate, cstate, step_ct, metrics = jax.shard_map(
             per_pod,
             mesh=mesh,
             in_specs=(repl(state.params), repl(state.opt),

@@ -25,7 +25,7 @@ from repro.analysis.lint import lint_tree
 from repro.analysis.memory import (check_memory, dot_read_bytes,
                                    peak_live_bytes)
 from repro.analysis.report import AnalysisReport, CheckRecord, Violation
-from repro.compat import make_mesh, shard_map as _shard_map
+from repro.compat import make_mesh
 from jax.sharding import PartitionSpec as P
 
 N, K = 32, 4
@@ -37,9 +37,9 @@ def _mesh():
 
 def _sharded(fn):
     mesh = _mesh()
-    return _shard_map(fn, mesh=mesh,
-                      in_specs=(P("data", None), P(None, None)),
-                      out_specs=P(None, None))
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(P("data", None), P(None, None)),
+                         out_specs=P(None, None))
 
 
 def _rules(violations):
@@ -82,7 +82,7 @@ def test_wrong_payload_fails_collective_payload():
     @_sharded
     def step(A_loc, Q):
         # psum of the (m_loc, k) product instead of the (n, k) iterate
-        return (A_loc.T @ jax.lax.psum(A_loc @ Q, "data"))[:N]
+        return jax.lax.psum(A_loc @ Q, "data")
 
     v, _ = check_step(trace_jaxpr(step, *_args()), ONE_PSUM, "payload")
     assert "collective-payload" in _rules(v)
@@ -131,7 +131,7 @@ def test_f64_upcast_fails():
     def step(A, Q):
         return (A @ Q).astype(jnp.float64)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = trace_jaxpr(step, *_args())
     v, _ = check_step(jx, StepContract(), "f64")
     assert "f64-upcast" in _rules(v)

@@ -2,7 +2,7 @@
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import AbstractMesh
+from jax.sharding import AbstractMesh
 
 from repro import sharding as Sh
 

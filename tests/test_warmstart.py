@@ -192,6 +192,7 @@ def test_svd_dryrun_appends_to_existing_xla_flags():
     """Regression: importing launch.svd_dryrun (and launch.dryrun) used
     to overwrite XLA_FLAGS, clobbering user/CI-provided flags."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # never the parent's chip
     env["XLA_FLAGS"] = "--xla_dump_to=/tmp/xla_dump_regression_test"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = ("import os\n"
