@@ -1,4 +1,4 @@
-"""Assemble EXPERIMENTS.md tables from dry-run / perf / svd artifacts.
+"""Assemble EXPERIMENTS.md tables from dry-run and svd artifacts.
 
     PYTHONPATH=src python -m benchmarks.report > results/experiments_tables.md
 """
@@ -47,37 +47,6 @@ def dryrun_table(mesh: str) -> str:
     return "\n".join(lines)
 
 
-def perf_table() -> str:
-    rows = []
-    for path in sorted(glob.glob(os.path.join(RESULTS, "perf", "*.json"))):
-        name = os.path.basename(path)[:-5]
-        d = json.load(open(path))
-        if "error" in d:
-            rows.append((name, None, d["error"][:80]))
-            continue
-        src = d.get("composed") or d.get("full", {})
-        full = d.get("full", {})
-        rows.append((name, {
-            "flops": src.get("flops", 0),
-            "bytes": src.get("bytes_accessed", 0),
-            "coll": src.get("collective_bytes_total", 0),
-            "temp": full.get("temp_size_in_bytes", 0),
-            "n_micro": d.get("n_micro"),
-        }, None))
-    lines = ["| experiment | n_micro | t_comp (s) | t_mem (s) | t_coll (s) "
-             "| temp GB/chip |", "|" + "---|" * 6]
-    for name, r, err in rows:
-        if err:
-            lines.append(f"| {name} | ERROR: {err} | | | | |")
-            continue
-        lines.append(
-            f"| {name} | {r['n_micro']} | "
-            f"{r['flops']/hw.PEAK_FLOPS:.3f} | "
-            f"{r['bytes']/hw.HBM_BW:.3f} | "
-            f"{r['coll']/hw.ICI_BW:.3f} | {r['temp']/1e9:.2f} |")
-    return "\n".join(lines)
-
-
 def svd_table() -> str:
     path = os.path.join(RESULTS, "svd_dryrun.json")
     if not os.path.exists(path):
@@ -109,8 +78,6 @@ def main():
     print(roofline.fmt_table(cells, "single"))
     print("\n## §Roofline — multi-pod\n")
     print(roofline.fmt_table(cells, "multi"))
-    print("\n## §Perf — hillclimb experiments\n")
-    print(perf_table())
     print("\n## §Perf — SVD power-step variants (paper 1TB dense problem)\n")
     print(svd_table())
 

@@ -367,7 +367,8 @@ class SVDResult(NamedTuple):
     #                          on the deflation engines)
     wall_time_s: Any = None  # end-to-end wall-clock seconds for the
     #                          svd() call (dispatch + solve + extract),
-    #                          stamped once by the front door so every
+    #                          stamped once by the front door after the
+    #                          factors are ready, so every
     #                          backend reports it and metering layers
     #                          (repro.serving) never clock the driver
     #                          from outside

@@ -224,14 +224,7 @@ class MemmapMatrix(HostBlockedMatrix):
         return blk
 
     def block(self, b: int) -> jax.Array:
-        blk = self.host_block(b)
-
-        def _put():
-            fault_hook("h2d", self.telemetry)
-            return jnp.asarray(blk)                # the H2D copy
-
-        dev = retry_io(_put, site="h2d", policy=self.retry_policy,
-                       telemetry=self.telemetry)
+        dev = super().block(b)
         self.fetches += 1
-        self.h2d_bytes += blk.nbytes
+        self.h2d_bytes += dev.nbytes
         return dev

@@ -70,6 +70,7 @@ from repro.core.faults import fault_hook, retry_io
 from repro.core.operator import host_sync_scalar
 from repro.core.precision import resolve_sweep_dtype
 from repro.core.partition import BatchPlan, make_batch_plan, symmetric_tasks
+from repro.core.spans import span
 
 
 def _f32dot(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -98,25 +99,33 @@ def _f32dot(a: jax.Array, b: jax.Array) -> jax.Array:
 @functools.lru_cache(maxsize=None)
 def hostblock_gram_step_fn():
     """``acc + blk^T blk`` — one block of the streamed Gram."""
-    return jax.jit(lambda acc, blk: acc + _f32dot(blk.T, blk))
+    def hostblock_gram_step(acc, blk):
+        return acc + _f32dot(blk.T, blk)
+    return jax.jit(hostblock_gram_step)
 
 
 @functools.lru_cache(maxsize=None)
 def hostblock_matvec_fn():
     """``blk @ v`` — one block of the streamed mat-vec."""
-    return jax.jit(lambda blk, v: _f32dot(blk, v))
+    def hostblock_matvec(blk, v):
+        return _f32dot(blk, v)
+    return jax.jit(hostblock_matvec)
 
 
 @functools.lru_cache(maxsize=None)
 def hostblock_matmat_fn():
     """``blk @ Q`` — one block of the streamed extraction pass."""
-    return jax.jit(lambda blk, Q: _f32dot(blk, Q))
+    def hostblock_matmat(blk, Q):
+        return _f32dot(blk, Q)
+    return jax.jit(hostblock_matmat)
 
 
 @functools.lru_cache(maxsize=None)
 def hostblock_rmatmat_step_fn():
     """``acc + blk^T y_b`` — one block of the streamed ``A^T Y``."""
-    return jax.jit(lambda acc, blk, yb: acc + _f32dot(blk.T, yb))
+    def hostblock_rmatmat_step(acc, blk, yb):
+        return acc + _f32dot(blk.T, yb)
+    return jax.jit(hostblock_rmatmat_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -141,7 +150,9 @@ def hostblock_chain_step_fn(stage_dtype: str):
 def hostblock_sketch_step_fn():
     """``acc + blk^T om_b`` — one block of the streamed range sketch
     (Omega row blocks generated on the fly, never resident)."""
-    return jax.jit(lambda acc, blk, om: acc + _f32dot(blk.T, om))
+    def hostblock_sketch_step(acc, blk, om):
+        return acc + _f32dot(blk.T, om)
+    return jax.jit(hostblock_sketch_step)
 
 
 @functools.lru_cache(maxsize=None)
@@ -149,8 +160,9 @@ def hostblock_deflate_step_fn():
     """``acc + blk^T (xv_b - u_b svtv)`` — one block of the fused Alg-4
     reverse sweep (``svtv`` passed as an argument, not closed over, so
     the compiled step is reused across deflation iterations)."""
-    return jax.jit(
-        lambda acc, blk, xvb, ub, svtv: acc + blk.T @ (xvb - ub @ svtv))
+    def hostblock_deflate_step(acc, blk, xvb, ub, svtv):
+        return acc + blk.T @ (xvb - ub @ svtv)
+    return jax.jit(hostblock_deflate_step)
 
 
 # ---------------------------------------------------------------------------
@@ -310,7 +322,8 @@ class HostBlockedMatrix:
 
         def _put():
             fault_hook("h2d", self.telemetry)
-            return jnp.asarray(blk)                # the H2D copy
+            with span("stage.h2d"):
+                return jnp.asarray(blk)            # the H2D copy
 
         return retry_io(_put, site="h2d", policy=self.retry_policy,
                         telemetry=self.telemetry)
@@ -325,7 +338,8 @@ class HostBlockedMatrix:
         for b in range(self.n_blocks):
             cur = nxt
             if b + 1 < self.n_blocks:
-                jax.block_until_ready(acc)     # pace: block b-1 consumed
+                with span("stage.pace"):   # block b-1 consumed
+                    jax.block_until_ready(acc)
                 nxt = self.block(b + 1)
             acc = step(acc, cur)
         return acc
@@ -340,7 +354,8 @@ class HostBlockedMatrix:
         for b in range(self.n_blocks):
             cur = nxt
             if b + 1 < self.n_blocks:  # prefetch next block (async H2D)
-                jax.block_until_ready(outs[-1:])  # pace: b-1 consumed
+                with span("stage.pace"):   # block b-1 consumed
+                    jax.block_until_ready(outs[-1:])
                 nxt = self.block(b + 1)
             outs.append(mv(cur, v))
         return jnp.concatenate(outs)
@@ -357,7 +372,8 @@ class HostBlockedMatrix:
         for b in range(self.n_blocks):
             cur = nxt
             if b + 1 < self.n_blocks:  # prefetch next block (async H2D)
-                jax.block_until_ready(outs[-1:])  # pace: b-1 consumed
+                with span("stage.pace"):   # block b-1 consumed
+                    jax.block_until_ready(outs[-1:])
                 nxt = self.block(b + 1)
             outs.append(mm(cur, Q))
         return jnp.concatenate(outs)
@@ -373,7 +389,8 @@ class HostBlockedMatrix:
             lo, hi = self.plan.bounds(b)
             cur = nxt
             if b + 1 < self.n_blocks:  # prefetch next block (async H2D)
-                jax.block_until_ready(acc)     # pace: block b-1 consumed
+                with span("stage.pace"):   # block b-1 consumed
+                    jax.block_until_ready(acc)
                 nxt = self.block(b + 1)
             acc = step(acc, cur, Y[lo:hi])
         return acc
@@ -390,7 +407,8 @@ class HostBlockedMatrix:
         for b in range(self.n_blocks):
             cur = nxt
             if b + 1 < self.n_blocks:  # prefetch next block (async H2D)
-                jax.block_until_ready(acc)     # pace: block b-1 consumed
+                with span("stage.pace"):   # block b-1 consumed
+                    jax.block_until_ready(acc)
                 nxt = self.block(b + 1)
             acc = step(acc, cur, Q)
         return acc

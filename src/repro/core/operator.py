@@ -79,6 +79,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.config import seed_to_key
 from repro.core.precision import resolve_sweep_dtype
+from repro.core.spans import span
 from repro.core.tsvd import (rayleigh_ritz_from_W, sweep_ops,
                              warm_start_width)
 
@@ -109,7 +110,8 @@ def host_sync_scalar(x):
     """
     if isinstance(x, (bool, int, float)):
         return x
-    return x.item()
+    with span("svd.sync"):
+        return x.item()
 
 
 # ---------------------------------------------------------------------------
@@ -704,7 +706,8 @@ class HostBlockedOperator(LinearOperator):
         for b in range(host.n_blocks):     # one pass; Omega never resident
             cur = nxt
             if b + 1 < host.n_blocks:      # prefetch next block (async H2D)
-                jax.block_until_ready(acc)     # pace: block b-1 consumed
+                with span("stage.pace"):   # block b-1 consumed
+                    jax.block_until_ready(acc)
                 nxt = host.block(b + 1)
             om_b = jax.random.normal(jax.random.fold_in(okey, b),
                                      (cur.shape[0], l), jnp.float32)

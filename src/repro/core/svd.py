@@ -77,6 +77,7 @@ from repro.core.operator import (DenseOperator, HostBlockedOperator,
                                  SparseStreamOperator, host_sync_scalar,
                                  warm_start_width)
 from repro.core.precision import fp32_dots, resolve_sweep_dtype
+from repro.core.spans import span
 
 __all__ = ["svd", "svd_update", "init_state", "step", "finalize",
            "SolverState", "SVDConfig", "SVDResult", "key_to_seed"]
@@ -217,7 +218,9 @@ def step(op: LinearOperator, state: SolverState,
     tel = getattr(op, "_telemetry", None)       # duck-typed operators
     fault_hook("device_oom", tel)               # chaos: OOM on dispatch
     p0, b0 = int(op.passes), dict(op.bytes_moved)
-    Z = maybe_corrupt("sweep", op.gram_chain(state.Q), tel)
+    with span("op.chain"):
+        Z = op.gram_chain(state.Q)
+    Z = maybe_corrupt("sweep", Z, tel)
     Qn = op.orth(Z)
     gap = op.subspace_gap(state.Q, Qn)  # device scalar on jax backends
     converged, prev_gap = False, state.prev_gap
@@ -257,8 +260,9 @@ def finalize(op: LinearOperator, state: SolverState,
         converged = bool(host_sync_scalar(state.gap) <= _tol(state, cfg))
     p0, b0 = int(op.passes), dict(op.bytes_moved)
     k = state.k
-    U, S, V = op.extract(state.Q)                      # one more pass
-    U, S, V = U[:, :k], S[:k], V[:, :k]                # drop oversampled
+    with span("svd.extract"):
+        U, S, V = op.extract(state.Q)                  # one more pass
+        U, S, V = U[:, :k], S[:k], V[:, :k]            # drop oversampled
     iters = np.full((k,), state.it, np.int32)
     final = _stamp(state, op, p0, b0, converged=converged)
     return SVDResult(U, S, V, iters, int(final.passes), op.bytes_per_pass,
@@ -412,7 +416,8 @@ def _drive(op: LinearOperator, k: int, cfg: SVDConfig, warm, mgr,
             break
         p0 = int(op.passes)
         try:
-            new = step(op, state, cfg)
+            with span("svd.iter"):
+                new = step(op, state, cfg)
         except NumericalHealthError as err:
             state, good, health_attempts = _recover(
                 op, cfg, err, good, health_attempts, telemetry,
@@ -894,14 +899,18 @@ def svd(A, k: int, *, mesh=None, axes=("data",),
     bytes_per_pass, converged, backend, bytes_moved, faults,
     wall_time_s).
     """
-    t0 = time.perf_counter()
-    with fp32_dots():
-        res = _dispatch(A, k, mesh=mesh, axes=axes, config=config,
-                        _warm=_warm, **overrides)
-    # one stamp at the front door covers every backend: metering layers
-    # (repro.serving) read the wall clock off the result instead of
-    # timing the driver from outside
-    return res._replace(wall_time_s=time.perf_counter() - t0)
+    with span("svd.solve"):
+        t0 = time.perf_counter()
+        with fp32_dots():
+            res = _dispatch(A, k, mesh=mesh, axes=axes, config=config,
+                            _warm=_warm, **overrides)
+        # one stamp at the front door covers every backend: metering
+        # layers (repro.serving) read the wall clock off the result
+        # instead of timing the driver from outside.  The lagged-sync
+        # tiers return with work still queued, so the stamp waits for
+        # the factors.
+        jax.block_until_ready((res.U, res.S, res.V))
+        return res._replace(wall_time_s=time.perf_counter() - t0)
 
 
 def _dispatch(A, k: int, *, mesh=None, axes=("data",),
