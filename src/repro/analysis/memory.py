@@ -10,9 +10,10 @@ Two estimates, both read off the traced jaxpr (no solve, no device):
   is checked against a per-device budget — the "does the step fit"
   proof the mesh-scale-up work needs before touching real hardware.
 
-* A-traffic — the bytes the step's ``dot_general``s actually read of
-  the A-sized operand (``dot_read_bytes``), or the bytes of the staged
-  block argument for the host-streamed step functions.  Summed over a
+* A-traffic — the bytes the step's ``dot_general``s and Pallas kernels
+  actually read of the A-sized operand (``dot_read_bytes``), or the
+  bytes of the staged block argument for the host-streamed step
+  functions.  Summed over a
   backend's step traces this must equal the solver's OWN accounting
   (``chain_passes * op.bytes_per_pass``), so the static estimate and
   the runtime ``passes``/``bytes_moved`` counters can't diverge: change
@@ -106,17 +107,20 @@ def collective_payload_bytes(jaxpr) -> int:
 
 
 def dot_read_bytes(jaxpr, a_nbytes: int) -> int:
-    """Bytes of A-sized ``dot_general`` operands read by the trace.
+    """Bytes of A-sized ``dot_general`` and ``pallas_call`` operands
+    read by the trace.
 
     An operand counts as "A-sized" when its aval is exactly
     ``a_nbytes`` — the shard/block of A at the sweep dtype.  Transposes
     and dtype casts of A keep the byte size, so the measure is stable
     under the sweeps' layout changes; iterate-sized (n, k) operands
-    never match.
+    never match.  A Pallas kernel's grid reads each block of its A
+    operand once, so the operand counts once, however many dots its
+    body runs on the blocks (those are block-sized, never A-sized).
     """
     total = 0
     for eqn in iter_eqns(jaxpr):
-        if _prim(eqn) == "dot_general":
+        if _prim(eqn) in ("dot_general", "pallas_call"):
             for v in eqn.invars:
                 if _var_bytes(v) == a_nbytes:
                     total += a_nbytes

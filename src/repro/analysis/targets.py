@@ -18,7 +18,8 @@ trace, contribute metadata groups (``nnz * itemsize`` vs the operator's
 Coverage (all six backends, per-config):
 
 =============  ==========================================================
-dense          block step + sketch + extract, fp32/bf16, dots accounting
+dense          block step + sketch + extract, fp32/bf16, dots accounting;
+               the fused Pallas block step (fp32, interpret mode)
 sharded        block step fp32/bf16 (twin-paired: identical collective
                bytes), warm sketch, extract, deflation faithful (3
                psums) vs opt (1 fused psum)
@@ -41,6 +42,7 @@ from repro.analysis.jaxpr_check import StepContract
 # Small trace shapes: tracing cost only, no solve.  M is divisible by
 # 1 and 8 host devices and by the 3-block staging plan.
 M, N, K = 384, 160, 8
+N_TILED = 256             # a width the fused dense chain tiles (n % 128 == 0)
 L = K + 8                 # oversampled sketch width (k + default oversample)
 N_BLOCKS = 3
 
@@ -78,6 +80,7 @@ def _dense_targets():
     from repro.core.config import seed_to_key
     from repro.core.operator import (DenseOperator, _dense_extract,
                                      _dense_sketch, dense_block_step_fn)
+    from repro.kernels.block_matvec import chain_tiles
 
     targets, groups = [], []
     for sd, itm in (("float32", 4), ("bfloat16", 2)):
@@ -93,6 +96,21 @@ def _dense_targets():
             (_sds((M, N), "float32"), _sds((N, K), "float32")),
             StepContract(requires_bf16=(sd == "bfloat16")),
             group=f"dense/chain/{sd}", a_nbytes=M * N * itm))
+    # the one-read Pallas chain a TPU runs (interpreted here), at a
+    # width that tiles: the kernel reads A once, as one pallas_call
+    # operand, so a fused operator counts chain_passes = 1
+    tiles = chain_tiles(M, N_TILED, K, "float32")
+    opf = DenseOperator(jnp.zeros((M, N_TILED), jnp.float32))
+    groups.append(AccountingGroup(
+        "dense/chain/fused/float32", "dots", 1 * opf.bytes_per_pass,
+        f"fused chain_passes(1) * bytes_per_pass({opf.bytes_per_pass})"))
+    targets.append(StepTarget(
+        "dense/block/fused/float32", "dense",
+        dense_block_step_fn("float32", tiles),
+        (_sds((M, N_TILED), "float32"), _sds((N_TILED, K), "float32")),
+        StepContract(), group="dense/chain/fused/float32",
+        a_nbytes=M * N_TILED * 4,
+        note=f"Pallas chain, tiles {tiles}, interpret mode"))
     op32 = DenseOperator(jnp.zeros((M, N), jnp.float32))
     groups.append(AccountingGroup(
         "dense/sketch/float32", "dots",

@@ -38,11 +38,13 @@ The protocol:
                            ``W = A Q``).
 ``passes``/``bytes_per_pass``
                            accounting.  Every A-sized call increments
-                           ``passes`` by its true cost: dense/sharded
-                           sweeps read ``A`` twice per ``gram_chain``
-                           (``chain_passes = 2``); the streamed backends
-                           fuse both halves into ONE stream of the data
-                           (``chain_passes = 1``).  ``bytes_per_pass``
+                           ``passes`` by its true cost: XLA's dense and
+                           sharded chains read ``A`` twice per
+                           ``gram_chain`` (``chain_passes = 2``); the
+                           streamed backends and the dense Pallas chain
+                           on a TPU fuse both halves into ONE read of
+                           the data (``chain_passes = 1``).
+                           ``bytes_per_pass``
                            is what one pass moves at the configured
                            sweep dtype, so ``passes * bytes_per_pass``
                            is the dominant data-movement cost.
@@ -91,6 +93,7 @@ __all__ = [
     "MemmapOperator",
     "SparseStreamOperator",
     "dense_block_step_fn",
+    "fused_chain_tiles",
     "sharded_block_step_fn",
     "host_sync_scalar",
     "warm_start_width",
@@ -135,8 +138,31 @@ def _gap(Q, Qn):
     return jnp.sum((Qn - Q @ (Q.T @ Qn)) ** 2)
 
 
-@functools.partial(jax.jit, static_argnames=("sweep_dtype",))
-def _dense_chain(X, Q, *, sweep_dtype):
+def fused_chain_tiles(platform, shape, l, sweep_dtype):
+    """The ``(row tile, column chunk)`` of the one-read Pallas chain for
+    an ``(m, n)`` float32 operand and an ``l``-wide iterate, or None for
+    the XLA two-dot chain.  Only a TPU compiles the kernel; the tiles
+    must divide ``A`` (padding would copy it on every call), and VMEM
+    must hold a row tile, ``Q`` and ``Z`` (``block_matvec.chain_tiles``).
+    """
+    if platform != "tpu":
+        return None
+    # Pallas is imported only where the kernel can run: it adds about
+    # a second to every process that imports it
+    from repro.kernels.block_matvec import chain_tiles
+    return chain_tiles(*shape, l, sweep_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("sweep_dtype", "tiles"))
+def _dense_chain(X, Q, *, sweep_dtype, tiles=None):
+    """``X^T (X Q)``: the one-read Pallas kernel under ``tiles`` (from
+    ``fused_chain_tiles``), else ``sweep_ops``' two XLA dots, which
+    read ``X`` twice.  Either way one program, ``jit__dense_chain``."""
+    if tiles is not None:
+        from repro.kernels import ops as kernel_ops
+        bm, bn = tiles
+        return kernel_ops.block_gram_chain(X, Q, bm=bm, bn=bn,
+                                           dtype=sweep_dtype)
     mm, rmm = sweep_ops(X, sweep_dtype)
     return rmm(mm(Q))
 
@@ -155,15 +181,17 @@ def _dense_extract(X, Q):
 
 
 @functools.lru_cache(maxsize=None)
-def dense_block_step_fn(sweep_dtype):
+def dense_block_step_fn(sweep_dtype, tiles=None):
     """ONE driver block step on the dense backend: the sweep-dtype gram
-    chain composed with the shared QR orthonormalization — the same two
-    jitted primitives ``core/svd.py::step`` dispatches per iteration
-    through ``DenseOperator``.  ``repro.analysis`` traces THIS function,
-    so the checked schedule can't drift from the solver."""
+    chain (the fused kernel under ``tiles``) composed with the shared QR
+    orthonormalization — the same two jitted primitives
+    ``core/svd.py::step`` dispatches per iteration through
+    ``DenseOperator``.  ``repro.analysis`` traces THIS function, so the
+    checked schedule can't drift from the solver."""
 
     def block_step(X, Q):
-        return _orth(_dense_chain(X, Q, sweep_dtype=sweep_dtype))
+        return _orth(_dense_chain(X, Q, sweep_dtype=sweep_dtype,
+                                  tiles=tiles))
 
     return jax.jit(block_step)
 
@@ -187,8 +215,9 @@ class LinearOperator:
     ground truth the accounting tests assert against.
     """
 
-    #: passes one ``gram_chain`` costs (2 = two A-sized sweeps; streamed
-    #: backends fuse both halves into one stream and override to 1)
+    #: passes one ``gram_chain`` costs (2 = two A-sized sweeps; the
+    #: streamed backends and the dense one-read kernel fuse both halves
+    #: into one read of ``A`` and set 1)
     chain_passes = 2
     #: passes one ``range_sketch`` costs
     sketch_passes = 1
@@ -379,10 +408,14 @@ class DenseOperator(LinearOperator):
     """An in-memory ``(M, N)`` jax array behind the protocol.
 
     Expects the tall orientation (M >= N); the front door transposes
-    wide inputs in and swaps the factors out (CSVD).  The two A-sized
-    sweeps of ``gram_chain`` (and the sketch) read the operand at
-    ``sweep_dtype`` with fp32 accumulation; ``matmat``/``extract`` stay
-    fp32 (``core/precision.py``).  ``lagged_sync``: the convergence
+    wide inputs in and swaps the factors out (CSVD).  ``gram_chain``
+    (and the sketch) contract the operand at ``sweep_dtype`` with fp32
+    accumulation; ``matmat``/``extract`` stay fp32
+    (``core/precision.py``).  On a TPU, where ``fused_chain_tiles``
+    finds tiles, the chain is the Pallas kernel that reads each row
+    tile of ``A`` once for both halves (``chain_passes = 1``);
+    elsewhere, or for an iterate too wide for VMEM, it is XLA's two
+    dots, two reads.  ``lagged_sync``: the convergence
     scalar is synced one iteration late so the driver's ``float()``
     lands after the next step is already dispatched — jax async dispatch
     keeps the device busy, at a bounded one-iteration overshoot.
@@ -395,6 +428,17 @@ class DenseOperator(LinearOperator):
         super().__init__()
         self._X = jnp.asarray(X, jnp.float32)
         self.sweep_dtype = resolve_sweep_dtype(sweep_dtype).name
+        devices = self._X.devices()
+        self._platform = (next(iter(devices)).platform
+                          if len(devices) == 1 else None)
+        # the kernel exists for this operand if it does for the
+        # narrowest iterate; gram_chain checks each iterate's width
+        if self._chain_tiles(8) is not None:
+            self.chain_passes = 1
+
+    def _chain_tiles(self, l):
+        return fused_chain_tiles(self._platform, self.shape, l,
+                                 self.sweep_dtype)
 
     @property
     def shape(self):
@@ -409,8 +453,10 @@ class DenseOperator(LinearOperator):
         return self._X.T @ Y
 
     def gram_chain(self, Q):
-        self._count(self.chain_passes)
-        return _dense_chain(self._X, Q, sweep_dtype=self.sweep_dtype)
+        tiles = self._chain_tiles(Q.shape[1])
+        self._count(1 if tiles else 2)
+        return _dense_chain(self._X, Q, sweep_dtype=self.sweep_dtype,
+                            tiles=tiles)
 
     def range_sketch(self, l, seed):
         self._count(self.sketch_passes)

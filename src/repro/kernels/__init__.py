@@ -4,8 +4,10 @@
 * ``deflate_matvec``  — fused Alg-4 deflated power step sweeps
 * ``block_matvec``    — multi-vector ``A Q`` / ``A^T Y`` sweeps for the
                         block subspace-iteration method (k columns per
-                        pass over A); takes the ``sweep_dtype`` policy's
-                        ``dtype`` (bf16 operands, fp32 accumulation)
+                        pass over A), and their chain ``A^T (A Q)`` in
+                        one read of A; takes the ``sweep_dtype``
+                        policy's ``dtype`` (bf16 operands, fp32
+                        accumulation)
 * ``local_attn``      — causal sliding-window flash attention (serving hot spot)
 
 Each kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` is the jit'd

@@ -125,24 +125,26 @@ def block_rmatvec(A, Y, *, bm: int = 512, bn: int = 512,
                              dtype=dtype)[:n, :k]
 
 
-def block_gram_chain(A, Q, *, bm: int = 512, bn: int = 512,
+def block_gram_chain(A, Q, *, bm: int = 256, bn: int = 8192,
                      interpret: bool | None = None, dtype=None):
-    """``A^T (A Q)`` via the fused multi-vector kernel pair (padded).
+    """``A^T (A Q)`` via the one-sweep Pallas kernel (padded); fp32 out.
 
-    Zero-padded rows/cols of ``A`` contribute nothing to either sweep,
-    and zero-padded k columns (lane alignment) stay zero through both,
-    so cropping ``Z`` back to ``(n, k)`` is exact.  ``dtype`` is the
-    sweep dtype of the precision policy — under bf16 both sweeps stream
-    a 2-byte ``A`` while accumulating fp32.
+    ``A`` is zero-padded to whole ``(bm, bn)`` tiles (zero rows and
+    columns add nothing to either half; callers that must not copy
+    ``A`` pick tiles with ``block_matvec.chain_tiles``) and ``Q``'s
+    ``l`` columns to a multiple of 8 sublanes (zero columns of ``Q``
+    give zero columns of ``Z``), so cropping ``Z`` to ``(n, l)`` is
+    exact.  ``dtype`` is the sweep dtype of the precision policy.
     """
     if interpret is None:
         interpret = _interpret()
     m, n = A.shape
-    k = Q.shape[1]
+    l = Q.shape[1]
+    bn = min(bn, -(-n // _LANE) * _LANE)
     Ap = _pad_to(A, (bm, bn))
-    Qp = _pad_to(Q, (bn, _LANE))
+    Qp = _pad_to(Q, (bn, 8))
     return _bm.block_gram_chain(Ap, Qp, bm=bm, bn=bn,
-                                interpret=interpret, dtype=dtype)[:n, :k]
+                                interpret=interpret, dtype=dtype)[:n, :l]
 
 
 def local_attention(q, k, v, *, window: int, softcap: float | None = None,

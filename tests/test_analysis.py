@@ -203,6 +203,20 @@ def test_dot_read_bytes_counts_only_a_sized_operands():
         == 2 * a_nbytes
 
 
+def test_dot_read_bytes_counts_a_kernel_operand_once():
+    """The one-read Pallas chain reads A as one kernel operand; the dots
+    in its body run on blocks and never count."""
+    from repro.kernels import ops
+
+    def step(A, Q):
+        return ops.block_gram_chain(A, Q, bm=128, bn=128, interpret=True)
+
+    A = jax.ShapeDtypeStruct((384, 256), jnp.float32)
+    Q = jax.ShapeDtypeStruct((256, 8), jnp.float32)
+    assert dot_read_bytes(trace_jaxpr(step, A, Q), 384 * 256 * 4) \
+        == 384 * 256 * 4
+
+
 # ---------------------------------------------------------------------------
 # lint pass
 # ---------------------------------------------------------------------------
@@ -355,3 +369,11 @@ def test_real_run_accounting_groups_match(full_report):
     assert acct, "accounting cross-checks missing"
     for c in acct:
         assert c.details["measured_bytes"] == c.details["expected_bytes"]
+
+
+def test_real_run_counts_one_read_for_the_fused_dense_chain(full_report):
+    from repro.analysis.targets import M, N_TILED
+    acct = {c.target: c.details for c in full_report.checks}
+    fused = acct["accounting:dense/chain/fused/float32"]
+    assert fused["measured_bytes"] == fused["expected_bytes"] \
+        == M * N_TILED * 4                      # one read of fp32 A

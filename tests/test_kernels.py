@@ -73,10 +73,15 @@ def test_block_matvec_sweep(m, n, k):
                                rtol=1e-3, atol=1e-2)
 
 
+# (512, 256, 40) and (640, 384, 13): several row tiles and column
+# chunks, the benchmark's l = 40 and an l that is not a multiple of 8
 @pytest.mark.parametrize("m,n,k", [(256, 128, 4), (300, 200, 8),
-                                   (512, 130, 16), (256, 128, 130)])
+                                   (512, 130, 16), (256, 128, 130),
+                                   (512, 256, 40), (640, 384, 13)])
 def test_block_gram_chain_sweep(m, n, k):
-    """Fused ``A^T (A Q)`` == oracle (block power / warm-start sweep)."""
+    """One-sweep ``A^T (A Q)`` == oracle (block power / warm-start
+    sweep), and off a float64 product by at most twice what XLA's
+    HIGHEST two-dot chain is off on the same inputs."""
     rng = np.random.default_rng(m * 7 + n + k)
     A = jnp.asarray(rng.normal(size=(m, n)).astype(np.float32))
     Q = jnp.asarray(rng.normal(size=(n, k)).astype(np.float32))
@@ -85,9 +90,16 @@ def test_block_gram_chain_sweep(m, n, k):
     want = block_gram_chain_ref(A, Q)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-3, atol=5e-2)
+    A64, Q64 = np.asarray(A, np.float64), np.asarray(Q, np.float64)
+    exact = A64.T @ (A64 @ Q64)
+    with jax.default_matmul_precision("highest"):
+        xla = np.asarray(jax.jit(lambda a, q: a.T @ (a @ q))(A, Q))
+    err = np.linalg.norm(np.asarray(got) - exact)
+    assert err <= 2 * np.linalg.norm(xla - exact)
 
 
-@pytest.mark.parametrize("m,n,k", [(256, 128, 4), (300, 200, 130)])
+@pytest.mark.parametrize("m,n,k", [(256, 128, 4), (300, 200, 130),
+                                   (512, 256, 40), (640, 384, 13)])
 @pytest.mark.parametrize("dtype", ["bfloat16", None])
 def test_block_kernels_sweep_dtype(m, n, k, dtype):
     """The kernels' mixed-precision contract (sweep_dtype operands, fp32

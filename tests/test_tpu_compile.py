@@ -21,12 +21,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
 
 from repro.compat import make_mesh
 from repro.core.oom import hostblock_chain_step_fn
-from repro.core.operator import _dense_chain, sharded_gram_chain_fn
+from repro.core.operator import (_dense_chain, fused_chain_tiles,
+                                 sharded_gram_chain_fn)
 from repro.core.precision import fp32_dots
 from repro.kernels import ops
 
 #: the paper's k and the dense per-chip shape (8 GiB fp32, half of HBM)
 M, N, K = 65536, 32768, 32
+#: the iterate's width: k plus the default oversample
+L = K + 8
 #: one v5e chip's HBM
 HBM_BYTES = 16 << 30
 DTYPES = ("float32", "bfloat16")
@@ -84,6 +87,27 @@ def test_dense_chain_fits_one_chip(one_chip, dtype):
         compiled = _dense_chain.lower(_spec((M, N), one_chip),
                                       _spec((N, K), one_chip),
                                       sweep_dtype=dtype).compile()
+    assert _hbm_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_dense_chain_fused_reads_a_in_place(one_chip, dtype, monkeypatch):
+    """On a TPU the chain program is still ``jit__dense_chain``, now
+    around the one-read kernel, and keeps no A-sized temporary (a bf16
+    copy of ``A`` would be the smallest)."""
+    tiles = fused_chain_tiles("tpu", (M, N), L, dtype)
+    assert tiles is not None
+    # the default backend here is the CPU, which would interpret
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    with fp32_dots():
+        compiled = _dense_chain.lower(_spec((M, N), one_chip),
+                                      _spec((N, L), one_chip),
+                                      sweep_dtype=dtype,
+                                      tiles=tiles).compile()
+    text = compiled.as_text()
+    assert re.search(r"^HloModule jit__dense_chain\b", text, re.M)
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < M * N * 2
     assert _hbm_bytes(compiled) < HBM_BYTES
 
 
